@@ -369,4 +369,6 @@ ALL = [algorithms_bench]
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

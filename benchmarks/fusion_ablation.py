@@ -288,4 +288,6 @@ ALL = [fusion_ablation]
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -55,15 +55,17 @@ def fig8_scaling():
     rows = []
     base = None
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH="src")
+    # The workers are a compile-only structural study on forced host
+    # devices: they must never contend with this process for an
+    # accelerator it may already hold.
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     for n in (1, 2, 4, 8):
         proc = subprocess.run([sys.executable, "-c", _WORKER, str(n)],
                               capture_output=True, text=True, env=env,
                               cwd=root, timeout=600)
         if proc.returncode != 0:
-            rows.append((f"fig8/devices{n}", float("nan"),
-                         f"error:{proc.stderr[-200:]}"))
-            continue
+            raise RuntimeError(f"fig8 worker for {n} devices failed:\n"
+                               f"{proc.stderr[-2000:]}")
         out = json.loads(proc.stdout.strip().splitlines()[-1])
         if base is None:
             base = out["flops_per_dev"]

@@ -9,6 +9,9 @@ in-memory tier or the out-of-core tier with identical results.
 import numpy as np
 
 from repro.core import fm
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # --- build a "dataset": 2M x 16 tall-and-skinny matrix --------------------
 n, p = 2_000_000, 16

@@ -14,6 +14,9 @@ import numpy as np
 
 from repro.core import fm
 from repro.core import materialize as mz
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 X_np = np.random.default_rng(0).normal(size=(50_000, 8)).astype(np.float32)
 X = fm.load_dense_matrix(X_np, "served_features")  # SSD-analog tier

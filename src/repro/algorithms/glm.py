@@ -2,7 +2,7 @@
 generalized to the gaussian/logistic/poisson families) on GenOps.
 
 Every IRLS iteration is ONE fused plan over X: the weighted Gram XᵀWX, the
-weighted moment XᵀWz and the log-likelihood sink co-materialize while a
+score Xᵀ(y − μ) and the log-likelihood sink co-materialize while a
 partition is resident in the fast tier, and the p×p Newton solve runs as a
 lazy EPILOGUE op in the SAME plan — one launch after the partial merge, on
 device, so the whole R expression below executes as a single DAG.  The
@@ -14,11 +14,16 @@ Equivalent FlashR R code (paper Fig. 4 style):
     eta <- X %*% beta
     mu  <- 1 / (1 + exp(-eta))                 # logistic link inverse
     w   <- mu * (1 - mu)
-    z   <- eta + (y - mu) / w                  # working response
     XtWX <- crossprod(X * w, X)                # weighted Gram  (sink)
-    XtWz <- crossprod(X, w * z)                # weighted moment (sink)
+    U    <- crossprod(X, y - mu)               # score          (sink)
     ll   <- sum(y * eta - log(1 + exp(eta)))   # log-likelihood (sink)
-    beta <- solve(XtWX, XtWz)                  # plan epilogue
+    beta <- beta + solve(XtWX, U)              # plan epilogue
+
+This is IRLS's ``solve(XtWX, crossprod(X, w * z))`` with working response
+``z = eta + (y - mu) / w``, since ``w * z = w * eta + (y - mu)``, written as
+a Newton increment: the moment XᵀWz carries the large XᵀWX·β term, and
+accumulating it in float32 over millions of rows costs β about κ(XᵀWX)·ε
+relative, where the increment's error shrinks with the step.
 
 Complexity per iteration: O(n·p²) compute, O(n·p) I/O — the correlation/SVD
 row of Table IV, with the same out-of-core behavior.
@@ -61,61 +66,62 @@ def _softplus(eta: fm.FM) -> fm.FM:
     return fm.pmax(eta, 0.0) + fm.log1p(fm.exp(-fm.abs_(eta)))
 
 
+def _beta_column(beta):
+    """``beta`` as a (p, 1) float32 column.  It may be a host array OR the
+    previous iteration's device-resident epilogue output: forwarding the
+    device value keeps iteration i's epilogue feeding iteration i+1's pass
+    with no host roundtrip — and since small operands sign the plan by
+    shape/dtype only, the plan cache still hits."""
+    if isinstance(beta, np.ndarray) or not hasattr(beta, "reshape"):
+        return np.asarray(beta, np.float32).reshape(-1, 1)
+    b = beta.reshape(-1, 1)
+    return b if str(b.dtype) == "float32" else b.astype(np.float32)
+
+
 def glm_irls_sinks(X: fm.FM, y: fm.FM, beta: np.ndarray, family: str):
     """The three sinks of one IRLS iteration (all lazy; co-materialize for
-    one fused pass over X): XᵀWX, XᵀWz, log-likelihood.
-
-    ``beta`` may be a host array OR the previous iteration's device-resident
-    epilogue output (the Newton solve result): forwarding the device value
-    keeps iteration i's epilogue feeding iteration i+1's pass as a broadcast
-    binding with no host roundtrip — and since small operands sign the plan
-    by shape/dtype only, the plan cache still hits."""
-    if isinstance(beta, np.ndarray) or not hasattr(beta, "reshape"):
-        b = np.asarray(beta, np.float32).reshape(-1, 1)
-    else:
-        b = beta.reshape(-1, 1)
-        if str(b.dtype) != "float32":
-            b = b.astype(np.float32)
-    eta = X @ b                                   # n×1, row-local
+    one fused pass over X): XᵀWX, the score Xᵀ(y − μ), log-likelihood."""
+    eta = X @ _beta_column(beta)                  # n×1, row-local
     if family == "gaussian":
         # Constant unit weights: IRLS is ordinary least squares, one step.
         # The sink is the RSS at the pre-step coefficients; glm() finishes
         # −RSS(β_new)/2 via the quadratic expansion on the small tier.
+        mu = eta
         w = y * 0.0 + 1.0
-        z = y
         ll = fm.sum_((y - eta) ** 2)
     elif family == "logistic":
         mu = fm.sigmoid(eta)
         w = mu * (1.0 - mu) + _W_EPS
-        z = eta + (y - mu) / w
         ll = fm.sum_(y * eta - _softplus(eta))
     elif family == "poisson":
         mu = fm.exp(eta)
         w = mu + _W_EPS
-        z = eta + (y - mu) / w
         ll = fm.sum_(y * eta - mu)
     else:
         raise ValueError(f"unknown family {family!r}; have {FAMILIES}")
     Xw = fm.mapply_col(X, w, "mul")               # X ⊙ w, row-local
     XtWX = fm.crossprod(Xw, X)                    # p×p weighted Gram sink
-    XtWz = fm.crossprod(X, w * z)                 # p×1 weighted moment sink
-    return XtWX, XtWz, ll
+    U = fm.crossprod(X, y - mu)                   # p×1 score sink
+    return XtWX, U, ll
 
 
 def glm_irls_outputs(X: fm.FM, y: fm.FM, beta: np.ndarray, family: str,
                      ridge: float = 0.0):
     """One WHOLE IRLS iteration as a single lazy DAG: the three sinks plus
-    ``beta_next = solve(XᵀWX (+ ridge·I), XᵀWz)`` running in the plan
-    epilogue — the Newton step materializes in the same fused pass over X.
-    Returns (beta_next, ll, XtWX, XtWz) lazy handles."""
-    XtWX, XtWz, ll = glm_irls_sinks(X, y, beta, family)
-    A = XtWX
+    the Newton step ``beta_next = beta + solve(XᵀWX + ridge·I, U −
+    ridge·beta)`` running in the plan epilogue — it materializes in the
+    same fused pass over X.  Returns (beta_next, ll, XtWX, U) lazy
+    handles."""
+    b = _beta_column(beta)
+    XtWX, U, ll = glm_irls_sinks(X, y, b, family)
+    A, rhs = XtWX, U
     if ridge:
-        # The ridge eye matrix is an epilogue-only source: handed whole to
-        # the post-merge callable, never streamed.
+        # The ridge eye matrix and the penalty's gradient are epilogue-only
+        # sources: handed whole to the post-merge callable, never streamed.
         A = A + fm.conv_R2FM((ridge * np.eye(X.ncol)).astype(np.float32))
-    beta_next = fm.solve(A, XtWz)
-    return beta_next, ll, XtWX, XtWz
+        rhs = rhs - fm.conv_R2FM(ridge * b)
+    beta_next = fm.conv_R2FM(b) + fm.solve(A, rhs)
+    return beta_next, ll, XtWX, U
 
 
 def glm_iteration_plan(X: fm.FM, y: fm.FM, beta: np.ndarray,
@@ -176,7 +182,7 @@ def glm(X: fm.FM, y: fm.FM, family: str = "logistic", *, max_iter: int = 25,
         # The ENTIRE iteration — sinks and the epilogue Newton solve — is
         # one plan: a single streaming pass over X and one epilogue launch
         # (plus the one-off moment pass when standardizing, iteration 1).
-        beta_fm, ll_fm, XtWX_fm, XtWz_fm = glm_irls_outputs(
+        beta_fm, ll_fm, XtWX_fm, U_fm = glm_irls_outputs(
             Z, y, beta_carry, family, ridge)
         moment_wants = ([mu_fm, sd_fm]
                         if standardize and center is None else [])
@@ -184,7 +190,7 @@ def glm(X: fm.FM, y: fm.FM, family: str = "logistic", *, max_iter: int = 25,
             # Also pull the (unridged) normal-equation sinks: the quadratic
             # RSS expansion below needs them on the small tier.
             beta_m, ll_m, A_m, b_m, *mo = fm.materialize(
-                beta_fm, ll_fm, XtWX_fm, XtWz_fm, *moment_wants, mode=mode,
+                beta_fm, ll_fm, XtWX_fm, U_fm, *moment_wants, mode=mode,
                 fuse=fuse, backend=backend)
         else:
             beta_m, ll_m, *mo = fm.materialize(
@@ -212,9 +218,10 @@ def glm(X: fm.FM, y: fm.FM, family: str = "logistic", *, max_iter: int = 25,
                 f"drop collinear columns")
         ll = float(fm.as_scalar(ll_m))
         if family == "gaussian":
-            # The streamed sink is RSS at the pre-step coefficients — zeros
-            # on this single OLS step, so it equals yᵀy.  Finish the
-            # quadratic expansion at the new beta on the small tier:
+            # The streamed sinks are at the pre-step coefficients — zeros on
+            # this single OLS step, so they are yᵀy and the score Xᵀy.
+            # Finish the quadratic expansion at the new beta on the small
+            # tier:
             # RSS(β) = yᵀy − 2βᵀXᵀy + βᵀ(XᵀX)β.
             A0 = fm.as_np(A_m).astype(np.float64)
             bvec = fm.as_np(b_m).astype(np.float64).reshape(-1)

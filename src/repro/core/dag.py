@@ -46,6 +46,12 @@ _ids = itertools.count()
 #: e.g. ``solve`` is never streamed through the partition loop.
 EPILOGUE_ONLY_KINDS = frozenset({"solve"})
 
+#: Matmul precision of every f32 contraction the eval rules trace.  The
+#: TPU's default f32 matmul rounds operands to bf16 in one MXU pass, which
+#: costs IRLS's X·β and XᵀWX about three significant digits; elsewhere the
+#: setting is a no-op.
+_F32 = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
 # Operands
@@ -197,7 +203,7 @@ class MapNode(Node):
                     base = jnp.ones_like(base)
                 elif agg_name == "count_nonzero":
                     base = (base != 0).astype(base.dtype)
-                return base @ onehot
+                return jnp.matmul(base, onehot, precision=_F32)
             raise NotImplementedError(
                 f"groupby_col with agg {agg_name!r}; supported: sum/count")
         if op == "solve":
@@ -213,7 +219,7 @@ class MapNode(Node):
                 b = b.reshape(a.shape[0], -1)  # (1, n) vector sink → column
             b = b.astype(self.dtype)
             x = jnp.linalg.solve(a, b)
-            r = b - a @ x
+            r = b - jnp.matmul(a, x, precision=_F32)
             return x + jnp.linalg.solve(a, r)
         raise AssertionError(f"unknown map op {op}")
 
@@ -238,7 +244,7 @@ def _inner_prod_block(a_blk, b_small, mul: vudf_mod.BinaryVUDF,
             return a_blk.matmul_small(b_small, out_dtype)
         a_blk = a_blk.todense()
     if mul.name == "mul" and add.name == "sum" and dtypes.is_floating(out_dtype):
-        return jnp.matmul(a_blk, b_small).astype(out_dtype)
+        return jnp.matmul(a_blk, b_small, precision=_F32).astype(out_dtype)
     t = mul.fn(a_blk[:, :, None], b_small[None, :, :])
     part = add.aggregate(t, 1, 0)
     return add.finalize(part).astype(out_dtype)
@@ -404,7 +410,7 @@ class InnerProdContractNode(SinkNode):
         if (self.mul.name == "mul" and self.add.name == "sum"
                 and dtypes.is_floating(self.acc_dtype)):
             part = jnp.matmul(a_blk.T.astype(self.acc_dtype),
-                              b_blk.astype(self.acc_dtype))
+                              b_blk.astype(self.acc_dtype), precision=_F32)
             return self.add.combine(acc, part)
         t = self.mul.fn(a_blk[:, :, None], b_blk[:, None, :])
         part = self.add.aggregate(t, 0, offset)

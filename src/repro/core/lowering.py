@@ -349,10 +349,23 @@ class _KernelUnit:
         self.kernel = kernel
         self.block_rows = int(block_rows)
 
+    #: Row-local segment nodes absorbed ahead of the kernel (e.g. the IRLS
+    #: weights feeding XᵀWX), evaluated generically before the kernel call
+    #: with the plan's small operands.
+    prefix = ()
+    plan = None
+
     @staticmethod
     def _merge(partials, node, part):
         partials[node.id] = node.combine(
             partials[node.id], part.astype(partials[node.id].dtype))
+
+    def _eval_prefix(self, values, smalls, offset):
+        for n in self.prefix:
+            blocks = [smalls[self.plan._small_pos[id(p)]]
+                      if isinstance(p, Small) else values[p.id]
+                      for p in n.parents]
+            values[n.id] = n.block_eval(blocks, offset)
 
 
 class ContractionUnit(_KernelUnit):
@@ -386,20 +399,24 @@ class ContractionUnit(_KernelUnit):
 class WeightedGramUnit(_KernelUnit):
     """The IRLS weighted-Gram pattern — ``mapply.col(X, w, mul)`` feeding an
     (mul, sum) contraction of the same X — → one kernels.wgram call: the
-    reweighted rows never exist outside the VMEM tile."""
+    reweighted rows never exist outside the VMEM tile.  ``prefix`` holds an
+    absorbed row-local chain computing w."""
 
     def __init__(self, node: InnerProdContractNode, x_id: int, w_id: int,
-                 block_rows: int):
+                 block_rows: int, *, plan=None, prefix=()):
         super().__init__("wgram", block_rows)
         self.node = node
         self.x_id = x_id
         self.w_id = w_id
+        self.plan = plan
+        self.prefix = tuple(prefix)
 
     def describe(self) -> str:
         return f"pallas:{self.kernel} root={self.node.name}"
 
     def run(self, values, partials, smalls, offset):
         from ..kernels import weighted_gram as wg
+        self._eval_prefix(values, smalls, offset)
         x = values[self.x_id]
         w = values[self.w_id]
         part = wg.wgram(x, w, block_rows=min(self.block_rows, x.shape[0]))
@@ -467,9 +484,9 @@ class SpmmUnit(_KernelUnit):
     staged SparseBlock flows straight into the kernel (nnz-proportional HBM
     traffic — the paper's one-hot/Criteo tier), scatter-densified to a VMEM
     tile inside the kernel only.  ``prefix`` holds an absorbed row-local
-    chain computing the dense right operand (the IRLS ``w·z`` feeding
-    XᵀWz), evaluated generically before the kernel call; it never touches
-    the sparse leaf (the matcher declines otherwise)."""
+    chain computing the dense right operand (the IRLS score's ``y − μ``) or
+    the weights of XᵀWX, evaluated generically before the kernel call; it
+    never touches the sparse leaf (the matcher declines otherwise)."""
 
     def __init__(self, kernel: str, node: InnerProdContractNode, *, plan,
                  seg, x_id: int, y_id: int | None = None,
@@ -491,11 +508,7 @@ class SpmmUnit(_KernelUnit):
 
     def run(self, values, partials, smalls, offset):
         from ..kernels import spmm
-        for n in self.prefix:
-            blocks = [smalls[self.plan._small_pos[id(p)]]
-                      if isinstance(p, Small) else values[p.id]
-                      for p in n.parents]
-            values[n.id] = n.block_eval(blocks, offset)
+        self._eval_prefix(values, smalls, offset)
         x = values[self.x_id]
         if not isinstance(x, SparseBlock):
             # Densified between tracing and execution (a tier move): the
@@ -515,11 +528,13 @@ class SpmmUnit(_KernelUnit):
     def _dense_part(self, x, values):
         x = x.astype(jnp.float32)
         if self.kernel == "spmm_gram":
-            return x.T @ x
-        if self.kernel == "spmm_xty":
-            return x.T @ values[self.y_id].astype(jnp.float32)
-        w = values[self.w_id].astype(jnp.float32).reshape(-1, 1)
-        return (x * w).T @ x
+            y = x
+        elif self.kernel == "spmm_xty":
+            y = values[self.y_id].astype(jnp.float32)
+        else:
+            y = x
+            x = x * values[self.w_id].astype(jnp.float32).reshape(-1, 1)
+        return jnp.matmul(x.T, y, precision=jax.lax.Precision.HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -595,6 +610,10 @@ def _is_pure_unary_chain(seg):
     return tuple(reversed(names))
 
 
+def _reads_sparse(nodes) -> bool:
+    return any(_sparse_leaf(p) for n in nodes for p in n.parents)
+
+
 def _match_spmm(plan, ir, claimed, reasons=None):
     """Sparse-ELL contraction → kernels.spmm — runs BEFORE the dense
     contraction matchers so a SparseBlock operand is never fed to the dense
@@ -605,9 +624,11 @@ def _match_spmm(plan, ir, claimed, reasons=None):
     * ``crossprod(Xs * w, Xs)`` — the absorbed ``mapply_col`` reweighting
       of the contraction's own sparse source → ``spmm_wgram`` (the sparse
       IRLS XᵀWX hot spot);
-    * ``crossprod(Xs, Y)``      — sparse left against a dense right; the
-      segment may have absorbed a row-local prefix computing Y (IRLS
-      XᵀWz's ``w·z``), which the unit evaluates generically first.
+    * ``crossprod(Xs, Y)``      — sparse left against a dense right.
+
+    The segment may have absorbed a row-local prefix computing w or Y (the
+    IRLS weights, the score's ``y − μ``), which the unit evaluates
+    generically first.
     """
     units = {}
     for seg in ir.segments:
@@ -652,28 +673,28 @@ def _match_spmm(plan, ir, claimed, reasons=None):
                      "(put the sparse matrix on the left)" if r_sp else
                      "sparse left against a non-float right operand")
             continue
-        # Multi-node segment: the wgram shape, or an absorbed dense prefix
+        # Multi-node segment: the wgram shape (its weights perhaps computed
+        # by an absorbed dense prefix), or an absorbed dense prefix
         # computing the right operand of an xty.
-        if len(seg.nodes) == 2:
-            m = seg.nodes[0]
-            other = right if left is m else left if right is m else None
-            if (isinstance(m, MapNode) and m.kind == "mapply_col"
-                    and m.fn_info["vudf"].name == "mul"
-                    and other is not None
-                    and not isinstance(other, Small)):
-                xx, ww = m.parents
-                if (_sparse_leaf(xx) and not _sparse_leaf(ww)
-                        and not isinstance(ww, Small)
-                        and _same_source(xx, other)
-                        and dtypes.is_floating(ww.dtype)):
-                    claimed.add(seg.sid)
-                    units[seg.sid] = SpmmUnit("spmm_wgram", node, plan=plan,
-                                              seg=seg, x_id=xx.id,
-                                              w_id=ww.id, absorb=(m,))
-                    continue
+        m = seg.nodes[-2]
+        other = right if left is m else left if right is m else None
+        if (isinstance(m, MapNode) and m.kind == "mapply_col"
+                and m.fn_info["vudf"].name == "mul"
+                and other is not None
+                and not isinstance(other, Small)):
+            xx, ww = m.parents
+            if (_sparse_leaf(xx) and not _sparse_leaf(ww)
+                    and not isinstance(ww, Small)
+                    and _same_source(xx, other)
+                    and dtypes.is_floating(ww.dtype)
+                    and not _reads_sparse(seg.nodes[:-2])):
+                claimed.add(seg.sid)
+                units[seg.sid] = SpmmUnit("spmm_wgram", node, plan=plan,
+                                          seg=seg, x_id=xx.id,
+                                          w_id=ww.id, absorb=(m,))
+                continue
         if l_sp and not isinstance(right, Small):
-            prefix = [n for n in seg.nodes if n is not node]
-            if any(_sparse_leaf(p) for n in prefix for p in n.parents):
+            if _reads_sparse(seg.nodes[:-1]):
                 _decline(reasons, seg, "absorbed prefix reads the sparse "
                          "source: spmm feeds the leaf to the kernel "
                          "unseen")
@@ -721,21 +742,22 @@ def _match_contractions(plan, ir, claimed, reasons=None):
 
 
 def _match_weighted_gram(plan, ir, claimed, reasons=None):
-    """crossprod(X * w, X) — a contraction segment that absorbed exactly one
-    ``mapply_col(·, ·, mul)`` reweighting of the contraction's own source —
-    → kernels.wgram.  XᵀWX is symmetric in which operand carries the
+    """crossprod(X * w, X) — a contraction segment ending in one
+    ``mapply_col(·, ·, mul)`` reweighting of the contraction's own source,
+    after an optional absorbed row-local prefix computing w — →
+    kernels.wgram.  XᵀWX is symmetric in which operand carries the
     weights, so both orientations match."""
     units = {}
     for seg in ir.segments:
         if seg.sid in claimed or seg.kind != "contraction":
             continue
-        if len(seg.nodes) != 2:
+        if len(seg.nodes) < 2:
             continue
-        m, node = seg.nodes
+        *prefix, m, node = seg.nodes
         if not isinstance(node, InnerProdContractNode) or \
                 not isinstance(m, MapNode) or m.kind != "mapply_col":
             continue
-        if any(_sparse_leaf(p) for p in node.parents + m.parents):
+        if _reads_sparse([node, m, *prefix]):
             _decline(reasons, seg, "sparse operand: the dense wgram "
                      "kernel reads dense tiles")
             continue
@@ -761,7 +783,8 @@ def _match_weighted_gram(plan, ir, claimed, reasons=None):
         if not all(dtypes.is_floating(p.dtype) for p in (xx, ww, other)):
             continue
         claimed.add(seg.sid)
-        units[seg.sid] = WeightedGramUnit(node, xx.id, ww.id, seg.block_rows)
+        units[seg.sid] = WeightedGramUnit(node, xx.id, ww.id, seg.block_rows,
+                                          plan=plan, prefix=prefix)
     return units
 
 

@@ -37,6 +37,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 # Buffer donation is the memory-chunk-recycling analog (DESIGN.md §1); when a
 # donated block has no same-shaped output XLA declines it — harmless, and on
@@ -50,12 +51,6 @@ from .fusion import Plan
 from .matrix import DenseStore, FMMatrix
 from ..observability import metrics
 from ..observability.trace import TRACER
-
-try:  # NamedSharding is only used when a mesh is passed.
-    from jax.sharding import NamedSharding, PartitionSpec as P
-except ImportError:  # pragma: no cover
-    NamedSharding = None
-    P = None
 
 
 # Compiled-plan cache: structurally identical DAG cuts (k-means iteration
@@ -114,7 +109,9 @@ _DAG_LOCK = threading.RLock()
 # ``combine`` path — exactly one per shard boundary (shards − 1 per pass
 # with sinks); ``bytes_in`` stays the UNION of rows read (each row is
 # staged by exactly one shard), with the per-shard split observable as the
-# ``shard_bytes_in`` tuple.
+# ``shard_bytes_in`` tuple, and the device ids each shard's partials
+# accumulated on as ``shard_devices``.  ``kernel_calls.<kernel>`` counts the
+# launches of each Pallas kernel unit (one per unit per partition step).
 EXEC_COUNTERS = (
     "materialize_calls",
     "plan_cache_hits",
@@ -141,6 +138,7 @@ def exec_stats() -> dict:
     st = {k: int(metrics.root_counter(k)) for k in EXEC_COUNTERS}
     st["pass_bytes_in"] = tuple(metrics.root_value("pass_bytes_in", ()))
     st["shard_bytes_in"] = tuple(metrics.root_value("shard_bytes_in", ()))
+    st["shard_devices"] = tuple(metrics.root_value("shard_devices", ()))
     return st
 
 
@@ -494,6 +492,9 @@ def _member_step(member, blocks, key_map, start, stop, *, donate_blocks,
     step = member.prog.step_donated if donate_blocks else member.prog.step
     mblocks = {nid: blocks[key] for nid, key in key_map.items()}
     metrics.inc("partition_steps")
+    # Kernel dispatch evidence: one launch of each kernel unit per step.
+    for unit in member.prog.kernel_units:
+        metrics.inc("kernel_calls." + unit.kernel)
     t0 = time.perf_counter()
     with TRACER.span("device_step", rows=stop - start, member=idx):
         partials, outputs = step(mblocks, member.smalls, member.bindings,
@@ -1090,6 +1091,9 @@ def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
             smalls = _to_device(m.smalls, dev)
             sm = _PassExec(m.ps, m.prog, m.sources, smalls, m.epi_sources,
                            bindings, out_nodes=m.out_nodes, scopes=m.scopes)
+            # The identity accumulators start on the shard's device, not
+            # wherever the default device put them.
+            sm.accs = _to_device(sm.accs, dev)
             sm.host_bufs = m.host_bufs
             sm.disk_stores = m.disk_stores
             clones.append(sm)
@@ -1148,6 +1152,13 @@ def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
                 if exc is not None:
                     raise exc
 
+    # Where each shard's partials accumulated — one device per shard when
+    # the sweep really spread over the mesh.
+    metrics.put("shard_devices", tuple(
+        tuple(sorted({d.id for sm in clones
+                      for leaf in jax.tree_util.tree_leaves(sm.accs)
+                      for d in leaf.devices()}))
+        for clones in clones_by_shard))
     dev0 = shards[0][3]
     for mi, m in enumerate(members):
         if m.ps.sinks:

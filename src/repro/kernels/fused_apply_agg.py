@@ -23,7 +23,9 @@ engine's pallas lowering (core/lowering.py) compiles eligible agg.col sink
 segments sharing one source into a single call, so N statistics cost one
 read of X.  ``fused_summary`` is the paper's six-statistic instance.
 
-Grid: 1-D over row blocks (the processor-level partition axis).
+Grid: 1-D over row blocks (the processor-level partition axis) of the
+lane-major view Xᵀ (common.py's layout policy): each statistic reduces
+along lanes into a (p, 1) accumulator.
 Accumulators live in VMEM scratch for the whole grid sweep (TPU grids
 execute sequentially per core), initialized at step 0 and written back at
 the last step — the same identity→update→combine contract as core/dag.py
@@ -43,7 +45,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import default_interpret, pad_rows, pick_block_rows
+from .common import (default_interpret, lane_block, lanes,
+                     pick_block_rows, row_mask)
 
 #: Aggregations the chain kernel can accumulate in a VMEM scratch register.
 CHAIN_AGGS = ("sum", "min", "max", "count", "count_nonzero")
@@ -90,7 +93,7 @@ def normalize_chains(chains, acc_dtype: str = "float32"):
     return tuple(out)
 
 
-def _chain_kernel(x_ref, nrows_ref, *refs, chains, block_rows):
+def _chain_kernel(xt_ref, nrows_ref, *refs, chains, block):
     n_out = len(chains)
     out_refs, accs = refs[:n_out], refs[n_out:]
     i = pl.program_id(0)
@@ -107,10 +110,9 @@ def _chain_kernel(x_ref, nrows_ref, *refs, chains, block_rows):
             else:
                 acc[...] = jnp.zeros_like(acc)
 
-    x = x_ref[...]
+    x = xt_ref[...]                        # (p, block): rows along lanes
     # Rows beyond the true length are padding: mask them out of every stat.
-    row_ids = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) + i * block_rows
-    valid = row_ids < nrows_ref[0]
+    valid = row_mask(nrows_ref[0], block)  # (1, block)
 
     for (unaries, agg, _), acc in zip(chains, accs):
         at = acc.dtype
@@ -120,19 +122,24 @@ def _chain_kernel(x_ref, nrows_ref, *refs, chains, block_rows):
         for u in unaries:
             v = _unary_fn(u)(v)
         if agg == "sum":
-            acc[...] += jnp.where(valid, v, 0).astype(at).sum(axis=0)
+            acc[...] += jnp.where(valid, v, 0).astype(at).sum(
+                axis=1, keepdims=True)
         elif agg == "count":
-            acc[...] += valid.astype(at).sum(axis=0)
+            acc[...] += jnp.broadcast_to(
+                valid, v.shape).astype(at).sum(axis=1, keepdims=True)
         elif agg == "count_nonzero":
-            acc[...] += (valid & (v != 0)).astype(at).sum(axis=0)
+            acc[...] += (valid & (v != 0)).astype(at).sum(
+                axis=1, keepdims=True)
         elif agg == "min":
             big = _acc_extreme(at, biggest=True)
             acc[...] = jnp.minimum(
-                acc[...], jnp.where(valid, v.astype(at), big).min(axis=0))
+                acc[...], jnp.where(valid, v.astype(at), big).min(
+                    axis=1, keepdims=True))
         elif agg == "max":
             small = _acc_extreme(at, biggest=False)
             acc[...] = jnp.maximum(
-                acc[...], jnp.where(valid, v.astype(at), small).max(axis=0))
+                acc[...], jnp.where(valid, v.astype(at), small).max(
+                    axis=1, keepdims=True))
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _writeback():
@@ -168,27 +175,26 @@ def fused_apply_agg(x, chains, *, acc_dtype: str = "float32",
     n, p = x.shape
     if not block_rows:
         block_rows = pick_block_rows(n, p, x.dtype, n_live=2)
-    xp, n_true = pad_rows(x, block_rows)
-    grid = (xp.shape[0] // block_rows,)
-    nrows = jnp.full((1,), n_true, jnp.int32)
+    bc = lane_block(n, block_rows)
+    xt = lanes(x, bc)
+    nrows = jnp.full((1,), n, jnp.int32)
 
-    kernel = functools.partial(_chain_kernel, chains=chains,
-                               block_rows=block_rows)
+    kernel = functools.partial(_chain_kernel, chains=chains, block=bc)
     outs = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(xt.shape[1] // bc,),
         in_specs=[
-            pl.BlockSpec((block_rows, p), lambda i: (i, 0)),
+            pl.BlockSpec((p, bc), lambda i: (0, i)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=[pl.BlockSpec((p,), lambda i: (0,))] * len(chains),
-        out_shape=[jax.ShapeDtypeStruct((p,), jnp.dtype(acc))
+        out_specs=[pl.BlockSpec((p, 1), lambda i: (0, 0))] * len(chains),
+        out_shape=[jax.ShapeDtypeStruct((p, 1), jnp.dtype(acc))
                    for _, _, acc in chains],
-        scratch_shapes=[pltpu.VMEM((p,), jnp.dtype(acc))
+        scratch_shapes=[pltpu.VMEM((p, 1), jnp.dtype(acc))
                         for _, _, acc in chains],
         interpret=interpret,
-    )(xp, nrows)
-    return tuple(outs)
+    )(xt, nrows)
+    return tuple(o.reshape(p) for o in outs)
 
 
 def fused_summary(x, *, block_rows: int = 0, interpret: bool | None = None):

@@ -6,8 +6,9 @@ analog is feeding the MXU from VMEM-resident tiles while the (p, p)
 accumulator never leaves VMEM for the whole sweep — one read of X, one
 write of G.
 
-Grid: 1-D over row blocks; f32 accumulation regardless of input dtype
-(bf16 in → f32 acc, the MXU-native mixed-precision mode).
+Grid: 1-D over row blocks of the lane-major view Xᵀ (common.py's layout
+policy); f32 accumulation regardless of input dtype (bf16 in → f32 acc,
+the MXU-native mixed-precision mode).
 Also provides ``xty`` (Xᵀ·Y for a second tall matrix) — the GMM M-step
 moment sink (X⊙r)ᵀX shares this code path.
 """
@@ -20,21 +21,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import default_interpret, pad_rows, pick_block_rows
+from .common import (default_interpret, lane_block, lane_contract, lanes,
+                     pick_block_rows)
 
 
-def _gram_kernel(x_ref, g_ref, acc, *, n_rows, block_rows):
+def _gram_kernel(xt_ref, g_ref, acc):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    x = x_ref[...]
+    xt = xt_ref[...]
     # Padding rows are zero — harmless for a sum-product contraction.
-    acc[...] += jax.lax.dot_general(
-        x, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += lane_contract(xt, xt)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _writeback():
@@ -48,30 +48,27 @@ def gram(x, *, block_rows: int = 0, interpret: bool | None = None):
     n, p = x.shape
     if not block_rows:
         block_rows = pick_block_rows(n, p, x.dtype, n_live=2)
-    xp, _ = pad_rows(x, block_rows)  # zero pad: neutral for sum-product
-    grid = (xp.shape[0] // block_rows,)
-    kernel = functools.partial(_gram_kernel, n_rows=n, block_rows=block_rows)
+    bc = lane_block(n, block_rows)
+    xt = lanes(x, bc)  # zero pad: neutral for sum-product
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, p), lambda i: (i, 0))],
+        _gram_kernel,
+        grid=(xt.shape[1] // bc,),
+        in_specs=[pl.BlockSpec((p, bc), lambda i: (0, i))],
         out_specs=pl.BlockSpec((p, p), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((p, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((p, p), jnp.float32)],
         interpret=interpret,
-    )(xp)
+    )(xt)
 
 
-def _xty_kernel(x_ref, y_ref, g_ref, acc):
+def _xty_kernel(xt_ref, yt_ref, g_ref, acc):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    acc[...] += jax.lax.dot_general(
-        x_ref[...], y_ref[...], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc[...] += lane_contract(xt_ref[...], yt_ref[...])
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _writeback():
@@ -86,16 +83,16 @@ def xty(x, y, *, block_rows: int = 0, interpret: bool | None = None):
     _, q = y.shape
     if not block_rows:
         block_rows = pick_block_rows(n, max(p, q), x.dtype, n_live=3)
-    xp, _ = pad_rows(x, block_rows)
-    yp, _ = pad_rows(y, block_rows)
-    grid = (xp.shape[0] // block_rows,)
+    bc = lane_block(n, block_rows)
+    xt = lanes(x, bc)
+    yt = lanes(y, bc)
     return pl.pallas_call(
         _xty_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, p), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, q), lambda i: (i, 0))],
+        grid=(xt.shape[1] // bc,),
+        in_specs=[pl.BlockSpec((p, bc), lambda i: (0, i)),
+                  pl.BlockSpec((q, bc), lambda i: (0, i))],
         out_specs=pl.BlockSpec((p, q), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((p, q), jnp.float32),
         scratch_shapes=[pltpu.VMEM((p, q), jnp.float32)],
         interpret=interpret,
-    )(xp, yp)
+    )(xt, yt)

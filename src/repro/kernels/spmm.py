@@ -7,17 +7,19 @@ arrives as an ELL slab (core/sparse.SparseBlock: ``cols`` int32 and
 these workloads are I/O bound: what matters is that HBM (≙ SSD) traffic is
 nnz-proportional, 2·kmax scalars per row instead of ncol.
 
-Inside the kernel each VMEM-resident slab is scatter-expanded to a dense
-(block_rows, p) tile —
+Inside the kernel each VMEM-resident slab — lane-major, (kmax, bc), as in
+common.py's layout policy — is expanded to a dense (p, bc) tile by
+comparing a sublane iota against each slot's column ids (Mosaic has no
+scatter):
 
-    rows = broadcasted_iota(...);  tile = zeros.at[rows, cols].add(vals)
+    tileᵀ = Σ_j (iota_p == cols[j]) · vals[j]        (kmax VPU selects)
 
-— and contracted on the MXU with ``dot_general``, exactly like the dense
-kernels.  The expansion never exists in HBM; padding entries are
-(col=0, val=0), neutral under scatter-ADD and sum-product contraction
-(same zero-padding argument as `gram.py`).  Grid, accumulator residency
-and writeback follow the `weighted_gram.py` template: 1-D grid over row
-blocks, (p, p) f32 accumulator in VMEM scratch for the whole sweep.
+— and contracted on the MXU, exactly like the dense kernels.  The
+expansion never exists in HBM; padding entries are (col=0, val=0), neutral
+under the sum and the sum-product contraction (same zero-padding argument
+as `gram.py`).  Grid, accumulator residency and writeback follow the
+`weighted_gram.py` template: 1-D grid over row blocks, (p, p) f32
+accumulator in VMEM scratch for the whole sweep.
 """
 from __future__ import annotations
 
@@ -28,20 +30,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import default_interpret, pad_rows, pick_block_rows
+from .common import (default_interpret, lane_block, lane_contract, lanes,
+                     pick_block_rows)
 
 
-def _scatter_tile(cols, vals, ncol: int):
-    """ELL slab → dense f32 (rows, ncol) tile, in-register/VMEM only."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, cols.shape, 0)
-    tile = jnp.zeros((cols.shape[0], ncol), jnp.float32)
-    return tile.at[rows, cols].add(vals.astype(jnp.float32))
+def _dense_tile(colst, valst, ncol: int):
+    """Lane-major ELL slab (kmax, bc) → dense f32 (ncol, bc) tile, built in
+    registers/VMEM by an iota compare per ELL slot."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, (ncol, colst.shape[1]), 0)
+    vals = valst.astype(jnp.float32)
+    tile = jnp.zeros(ids.shape, jnp.float32)
+    for j in range(colst.shape[0]):
+        tile += jnp.where(ids == colst[j:j + 1, :], vals[j:j + 1, :], 0.0)
+    return tile
 
 
 def _spmm_block_rows(n: int, kmax: int, p: int, dtype) -> int:
     # Live tiles per block: the slab (2 arrays, kmax wide) plus the
-    # scatter-expanded (rows, p) tile — budget on the widest.
+    # expanded (p, bc) tile — budget on the widest.
     return pick_block_rows(n, max(p, 2 * kmax), dtype, n_live=2)
+
+
+def _slabs(cols, vals, bc):
+    return lanes(cols, bc, value=0), lanes(vals, bc, value=0)
 
 
 def _spmm_gram_kernel(cols_ref, vals_ref, g_ref, acc, *, ncol):
@@ -51,10 +62,8 @@ def _spmm_gram_kernel(cols_ref, vals_ref, g_ref, acc, *, ncol):
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    x = _scatter_tile(cols_ref[...], vals_ref[...], ncol)
-    acc[...] += jax.lax.dot_general(
-        x, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    xt = _dense_tile(cols_ref[...], vals_ref[...], ncol)
+    acc[...] += lane_contract(xt, xt)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _writeback():
@@ -70,33 +79,30 @@ def spmm_gram(cols, vals, *, ncol: int, block_rows: int = 0,
     n, kmax = cols.shape
     if not block_rows:
         block_rows = _spmm_block_rows(n, kmax, ncol, vals.dtype)
-    cp, _ = pad_rows(cols, block_rows, value=0)
-    vp, _ = pad_rows(vals, block_rows, value=0)
-    grid = (cp.shape[0] // block_rows,)
+    bc = lane_block(n, block_rows)
+    ct, vt = _slabs(cols, vals, bc)
     kernel = functools.partial(_spmm_gram_kernel, ncol=ncol)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, kmax), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, kmax), lambda i: (i, 0))],
+        grid=(ct.shape[1] // bc,),
+        in_specs=[pl.BlockSpec((kmax, bc), lambda i: (0, i)),
+                  pl.BlockSpec((kmax, bc), lambda i: (0, i))],
         out_specs=pl.BlockSpec((ncol, ncol), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((ncol, ncol), jnp.float32),
         scratch_shapes=[pltpu.VMEM((ncol, ncol), jnp.float32)],
         interpret=interpret,
-    )(cp, vp)
+    )(ct, vt)
 
 
-def _spmm_xty_kernel(cols_ref, vals_ref, y_ref, g_ref, acc, *, ncol):
+def _spmm_xty_kernel(cols_ref, vals_ref, yt_ref, g_ref, acc, *, ncol):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    x = _scatter_tile(cols_ref[...], vals_ref[...], ncol)
-    acc[...] += jax.lax.dot_general(
-        x, y_ref[...].astype(jnp.float32), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    xt = _dense_tile(cols_ref[...], vals_ref[...], ncol)
+    acc[...] += lane_contract(xt, yt_ref[...].astype(jnp.float32))
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _writeback():
@@ -113,22 +119,21 @@ def spmm_xty(cols, vals, y, *, ncol: int, block_rows: int = 0,
     q = y.shape[1]
     if not block_rows:
         block_rows = _spmm_block_rows(n, kmax, max(ncol, q), vals.dtype)
-    cp, _ = pad_rows(cols, block_rows, value=0)
-    vp, _ = pad_rows(vals, block_rows, value=0)
-    yp, _ = pad_rows(y, block_rows)
-    grid = (cp.shape[0] // block_rows,)
+    bc = lane_block(n, block_rows)
+    ct, vt = _slabs(cols, vals, bc)
+    yt = lanes(y, bc)
     kernel = functools.partial(_spmm_xty_kernel, ncol=ncol)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, kmax), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, kmax), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, q), lambda i: (i, 0))],
+        grid=(ct.shape[1] // bc,),
+        in_specs=[pl.BlockSpec((kmax, bc), lambda i: (0, i)),
+                  pl.BlockSpec((kmax, bc), lambda i: (0, i)),
+                  pl.BlockSpec((q, bc), lambda i: (0, i))],
         out_specs=pl.BlockSpec((ncol, q), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((ncol, q), jnp.float32),
         scratch_shapes=[pltpu.VMEM((ncol, q), jnp.float32)],
         interpret=interpret,
-    )(cp, vp, yp)
+    )(ct, vt, yt)
 
 
 def _spmm_wgram_kernel(cols_ref, vals_ref, w_ref, g_ref, acc, *, ncol):
@@ -138,11 +143,9 @@ def _spmm_wgram_kernel(cols_ref, vals_ref, w_ref, g_ref, acc, *, ncol):
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    x = _scatter_tile(cols_ref[...], vals_ref[...], ncol)
-    w = w_ref[...].astype(jnp.float32)  # (block_rows, 1), broadcasts per row
-    acc[...] += jax.lax.dot_general(
-        x * w, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    xt = _dense_tile(cols_ref[...], vals_ref[...], ncol)
+    w = w_ref[...].astype(jnp.float32)  # (1, bc), broadcasts per row
+    acc[...] += lane_contract(xt * w, xt)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _writeback():
@@ -157,22 +160,20 @@ def spmm_wgram(cols, vals, w, *, ncol: int, block_rows: int = 0,
     the sparse IRLS hot spot.  Zero-padded w rows are neutral."""
     interpret = default_interpret() if interpret is None else interpret
     n, kmax = cols.shape
-    w = w.reshape(n, 1)
     if not block_rows:
         block_rows = _spmm_block_rows(n, kmax, ncol, vals.dtype)
-    cp, _ = pad_rows(cols, block_rows, value=0)
-    vp, _ = pad_rows(vals, block_rows, value=0)
-    wp, _ = pad_rows(w, block_rows)
-    grid = (cp.shape[0] // block_rows,)
+    bc = lane_block(n, block_rows)
+    ct, vt = _slabs(cols, vals, bc)
+    wt = lanes(w.reshape(n, 1), bc)
     kernel = functools.partial(_spmm_wgram_kernel, ncol=ncol)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, kmax), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, kmax), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
+        grid=(ct.shape[1] // bc,),
+        in_specs=[pl.BlockSpec((kmax, bc), lambda i: (0, i)),
+                  pl.BlockSpec((kmax, bc), lambda i: (0, i)),
+                  pl.BlockSpec((1, bc), lambda i: (0, i))],
         out_specs=pl.BlockSpec((ncol, ncol), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((ncol, ncol), jnp.float32),
         scratch_shapes=[pltpu.VMEM((ncol, ncol), jnp.float32)],
         interpret=interpret,
-    )(cp, vp, wp)
+    )(ct, vt, wt)

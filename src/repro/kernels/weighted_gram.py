@@ -12,8 +12,9 @@ lowers it onto this kernel, so the elementwise reweighting never exists in
 HBM — X and w stream through VMEM once and only the (p, p) accumulator
 persists across the grid sweep, exactly like `gram.py`.
 
-Grid: 1-D over row blocks; zero row padding is neutral (padded w rows are
-zero, so their outer products vanish).
+Grid: 1-D over row blocks of the lane-major views Xᵀ (p, bc) and wᵀ
+(1, bc); zero row padding is neutral (padded w rows are zero, so their
+outer products vanish).
 """
 from __future__ import annotations
 
@@ -24,21 +25,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import default_interpret, pad_rows, pick_block_rows
+from .common import (default_interpret, lane_block, lane_contract, lanes,
+                     pick_block_rows)
 
 
-def _wgram_kernel(x_ref, w_ref, g_ref, acc):
+def _wgram_kernel(xt_ref, w_ref, g_ref, acc):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    x = x_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)  # (block_rows, 1), broadcasts per row
-    acc[...] += jax.lax.dot_general(
-        x * w, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    xt = xt_ref[...].astype(jnp.float32)
+    w = w_ref[...].astype(jnp.float32)  # (1, bc), broadcasts per row
+    acc[...] += lane_contract(xt * w, xt)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _writeback():
@@ -54,19 +54,18 @@ def wgram(x, w, *, block_rows: int = 0, interpret: bool | None = None):
     """
     interpret = default_interpret() if interpret is None else interpret
     n, p = x.shape
-    w = w.reshape(n, 1)
     if not block_rows:
         block_rows = pick_block_rows(n, p, x.dtype, n_live=3)
-    xp, _ = pad_rows(x, block_rows)  # zero pad: neutral under zero weights
-    wp, _ = pad_rows(w, block_rows)
-    grid = (xp.shape[0] // block_rows,)
+    bc = lane_block(n, block_rows)
+    xt = lanes(x, bc)  # zero pad: neutral under zero weights
+    wt = lanes(w.reshape(n, 1), bc)
     return pl.pallas_call(
         _wgram_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, p), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
+        grid=(xt.shape[1] // bc,),
+        in_specs=[pl.BlockSpec((p, bc), lambda i: (0, i)),
+                  pl.BlockSpec((1, bc), lambda i: (0, i))],
         out_specs=pl.BlockSpec((p, p), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((p, p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((p, p), jnp.float32)],
         interpret=interpret,
-    )(xp, wp)
+    )(xt, wt)

@@ -12,22 +12,14 @@ reduction, no parameter or activation sharding crosses it.
 from __future__ import annotations
 
 import jax
-
-
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """`jax.make_mesh` kwargs for `n_axes` Auto axes, across jax versions:
-    jax < 0.5 has neither `jax.sharding.AxisType` nor the `axis_types`
-    parameter (Auto is the only behavior), so pass nothing there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n: int = 0, *, model: int = 1):
@@ -35,4 +27,4 @@ def make_host_mesh(n: int = 0, *, model: int = 1):
     n = n or len(jax.devices())
     data = n // model
     return jax.make_mesh((data, model), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)

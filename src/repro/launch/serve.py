@@ -66,7 +66,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO, format="%(message)s")
 
-    from repro.core import fm
+    from repro.core import fm, lowering
     from repro.core import materialize as mz
     from repro.core import matrix as matrix_mod
     from repro.observability import metrics
@@ -134,7 +134,8 @@ def main(argv=None):
             lat = sorted(latencies_us)
             record = {
                 "bench": "serve", "workload": "mixed-analytics",
-                "arm": arm, "mode": "disk", "backend": "xla",
+                "arm": arm, "mode": "disk",
+                "backend": lowering.resolve_backend(),
                 "n": args.n, "p": args.p,
                 "clients": k, "waves": waves, "requests": n_requests,
                 "us_per_call": round(1e6 * wall_s / n_requests, 1),
@@ -179,4 +180,6 @@ def run(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

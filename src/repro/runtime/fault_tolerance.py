@@ -61,14 +61,14 @@ def replan_mesh(n_devices: int, *, prefer_model: int = 16):
     stay valid), otherwise falls back to the largest power-of-two divisor —
     the elastic-scaling policy after losing hosts."""
     import jax
+    from jax.sharding import AxisType
 
-    from ..launch.mesh import mesh_axis_kwargs
     model = prefer_model
     while model > 1 and n_devices % model:
         model //= 2
     data = n_devices // model
     return jax.make_mesh((data, model), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def rescale_grad_accum(cfg_accum: int, old_data: int, new_data: int) -> int:

@@ -8,6 +8,7 @@ from repro.core import fm
 from repro.algorithms import (correlation, glm, gmm, kmeans, naive_bayes,
                               nb_predict, nmf, pca, summary, svd_tall)
 from repro.algorithms.glm import glm_iteration_plan
+from repro.algorithms.reference import irls_logistic
 
 RNG = np.random.default_rng(11)
 
@@ -76,24 +77,6 @@ def test_gmm_loglik_monotone(blobs, host):
 # GLM / IRLS
 # ---------------------------------------------------------------------------
 
-def _numpy_irls_logistic(X, y, max_iter=25, tol=1e-8, w_eps=1e-6):
-    """Reference IRLS with the same weight floor as algorithms/glm.py."""
-    beta = np.zeros(X.shape[1])
-    Xf = X.astype(np.float64)
-    prev = -np.inf
-    for _ in range(max_iter):
-        eta = Xf @ beta
-        mu = 1.0 / (1.0 + np.exp(-eta))
-        w = mu * (1 - mu) + w_eps
-        z = eta + (y - mu) / w
-        beta = np.linalg.solve(Xf.T @ (Xf * w[:, None]), Xf.T @ (w * z))
-        ll = float(np.sum(y * eta - np.logaddexp(0.0, eta)))
-        if np.isfinite(prev) and abs(ll - prev) <= tol * (abs(prev) + 1.0):
-            break
-        prev = ll
-    return beta
-
-
 @pytest.fixture(scope="module")
 def logit_data():
     X = RNG.normal(size=(4000, 6)).astype(np.float32)
@@ -108,11 +91,30 @@ def test_glm_logistic_matches_numpy_irls(logit_data, host):
     X, y = logit_data
     res = glm(fm.conv_R2FM(X, host=host), fm.conv_R2FM(y, host=host),
               family="logistic")
-    ref = _numpy_irls_logistic(X, y)
+    ref = irls_logistic(X, y)
     np.testing.assert_allclose(res.beta, ref, rtol=1e-5, atol=1e-6)
     assert res.converged
     t = np.array(res.loglik_trace)
     assert (np.diff(t) > -1e-6 * np.abs(t[:-1])).all()  # IRLS ascends
+
+
+def test_glm_logistic_float32_accuracy_at_scale():
+    """2^18 rows of a well-separated Gaussian mixture (κ(XᵀWX) ≈ 400): the
+    float32 engine stays within 1e-6 of the float64 IRLS.  Accumulating
+    the moment XᵀWz in float32 missed this by about 3x; the Newton
+    increment from the score Xᵀ(y − μ) does not."""
+    rng = np.random.default_rng(0)
+    n, p, k = 1 << 18, 32, 10
+    means = rng.standard_normal((k, p)).astype(np.float32) * 8
+    X = rng.standard_normal((n, p), dtype=np.float32)
+    X += means[rng.integers(0, k, n)]
+    eta = X @ (rng.standard_normal(p) / (8 * np.sqrt(p))).astype(np.float32)
+    y = (rng.random(n) < 1 / (1 + np.exp(-eta))).astype(np.float32)
+    y = y.reshape(-1, 1)
+    res = glm(fm.conv_R2FM(X), fm.conv_R2FM(y), family="logistic",
+              max_iter=4)
+    ref = irls_logistic(X, y, max_iter=4)
+    np.testing.assert_allclose(res.beta, ref, rtol=1e-5, atol=1e-6)
 
 
 def test_glm_logistic_ooc_disk_one_pass_and_wgram_dispatch(
@@ -141,7 +143,7 @@ def test_glm_logistic_ooc_disk_one_pass_and_wgram_dispatch(
     assert "wgram" in kernels, plan.program("pallas").describe()
 
     res = glm(Xd, yd, family="logistic")
-    ref = _numpy_irls_logistic(X, y)
+    ref = irls_logistic(X, y)
     np.testing.assert_allclose(res.beta, ref, rtol=1e-5, atol=1e-6)
 
 

@@ -71,15 +71,11 @@ def test_int8_crosspod_gradient_reduction():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import json, functools
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:  # jax < 0.6 keeps it under experimental
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.optim import compression
-        from repro.launch.mesh import mesh_axis_kwargs
 
-        mesh = jax.make_mesh((4,), ("pod",), **mesh_axis_kwargs(1))
+        mesh = jax.make_mesh((4,), ("pod",), axis_types=(AxisType.Auto,))
         rng = np.random.default_rng(0)
         # per-pod gradients (leading axis = pod shard)
         g_all = jnp.asarray(rng.normal(size=(4, 256)) * 1e-3, jnp.float32)

@@ -65,9 +65,8 @@ def test_checkpoint_async(tmp_path):
 
 def test_elastic_restore_resharded(tmp_path):
     """Save, then restore with explicit (new-mesh) shardings."""
-    from repro.launch.mesh import mesh_axis_kwargs
-    mesh = jax.make_mesh((1,), ("data",), **mesh_axis_kwargs(1))
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     ck = Checkpointer(tmp_path)
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     ck.save(1, tree, blocking=True)
