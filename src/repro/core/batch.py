@@ -93,10 +93,8 @@ def _plan_request(req: BatchRequest, backend: str, mesh,
         return False
     with metrics.use_scopes(_request_stack(req)):
         metrics.inc("materialize_calls")
-        with mz._DAG_LOCK:
-            req.plan = Plan(virtuals)
-            req.exec_plan = mz._acquire_exec_plan(
-                req.plan, backend, mesh, reuse_plans)
+        req.plan, req.exec_plan = mz._build_plan(virtuals, backend, mesh,
+                                                 reuse_plans)
     prog = req.exec_plan.program(backend)
     req.pass_progs = getattr(prog, "passes", None) or [prog]
     return True

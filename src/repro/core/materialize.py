@@ -50,7 +50,7 @@ from .dag import LeafNode, Node, as_node, wrap
 from .fusion import Plan
 from .matrix import DenseStore, FMMatrix
 from ..observability import metrics
-from ..observability.trace import TRACER
+from ..observability.trace import TRACER, next_seq
 
 
 # Compiled-plan cache: structurally identical DAG cuts (k-means iteration
@@ -201,9 +201,7 @@ def materialize(*mats: FMMatrix, mode: str = "auto", fuse: bool = True,
                                backend=backend)
         return [_result_of(m) for m in mats]
 
-    with _DAG_LOCK:
-        plan = Plan(virtuals)
-        exec_plan = _acquire_exec_plan(plan, backend, mesh, reuse_plans)
+    plan, exec_plan = _build_plan(virtuals, backend, mesh, reuse_plans)
 
     # A cached plan's nodes belong to the FIRST caller's live DAG.  The
     # execution reads schedule/program state from the (possibly borrowed)
@@ -231,6 +229,19 @@ def _result_of(m: FMMatrix) -> FMMatrix:
     store = getattr(m.node, "cached_store", None)
     assert store is not None, f"{m.node} failed to materialize"
     return store
+
+
+def _build_plan(virtuals, backend: str, mesh, reuse_plans: bool):
+    """(``Plan(virtuals)``, its executable plan from `_acquire_exec_plan`),
+    shared by ``materialize`` and the batch executor: built under
+    ``_DAG_LOCK`` (plan construction classifies live DAG node state), in a
+    ``plan`` span, timed into ``plan_seconds``."""
+    t0 = time.perf_counter()
+    with TRACER.span("plan", outputs=len(virtuals)), _DAG_LOCK:
+        plan = Plan(virtuals)
+        exec_plan = _acquire_exec_plan(plan, backend, mesh, reuse_plans)
+    metrics.inc("plan_seconds", time.perf_counter() - t0)
+    return plan, exec_plan
 
 
 def _acquire_exec_plan(plan: Plan, backend: str, mesh, reuse_plans: bool):
@@ -395,9 +406,12 @@ class _PassExec:
     def route_outputs(self, start: int, stop: int, outputs: dict):
         for nid, val in outputs.items():
             if nid in self.disk_stores:
-                self.disk_stores[nid].write_rows(start, np.asarray(val))
+                with TRACER.span("fetch"):
+                    val = np.asarray(val)
+                self.disk_stores[nid].write_rows(start, val)
             elif nid in self.host_bufs:
-                self.host_bufs[nid][start:stop] = np.asarray(val)
+                with TRACER.span("fetch"):
+                    self.host_bufs[nid][start:stop] = np.asarray(val)
             else:
                 self.out_parts[nid].append(val)
 
@@ -499,8 +513,6 @@ def _member_step(member, blocks, key_map, start, stop, *, donate_blocks,
     with TRACER.span("device_step", rows=stop - start, member=idx):
         partials, outputs = step(mblocks, member.smalls, member.bindings,
                                  jnp.asarray(start, jnp.int32))
-        if TRACER.enabled:  # timing fidelity while tracing only
-            jax.block_until_ready((partials, outputs))
     metrics.inc("device_step_seconds", time.perf_counter() - t0)
     # The paper's partial-merge: each partition's sink partials fold into
     # the member's running accumulators with the aggregation VUDFs'
@@ -508,8 +520,6 @@ def _member_step(member, blocks, key_map, start, stop, *, donate_blocks,
     t0 = time.perf_counter()
     with TRACER.span("combine", member=idx):
         member.accs = member.prog.combine(member.accs, partials)
-        if TRACER.enabled:
-            jax.block_until_ready(member.accs)
     metrics.inc("combine_seconds", time.perf_counter() - t0)
     return outputs
 
@@ -676,8 +686,9 @@ def _execute_passes(plan: Plan, *, onto: Optional[Plan] = None,
         member = _PassExec(ps, pprog, ps_src, smalls, ps_epi, bindings,
                            out_nodes=out_nodes)
         t_pass = time.perf_counter()
+        seq = next_seq()
         with TRACER.span("pass", idx=ps.idx, mode=mode,
-                         partition_rows=ps.partition_rows):
+                         partition_rows=ps.partition_rows, seq=seq):
             if mode == "whole":
                 _run_whole_group([member], mesh=mesh)
                 residents = None
@@ -699,7 +710,7 @@ def _execute_passes(plan: Plan, *, onto: Optional[Plan] = None,
                 entry = _run_stream_group(
                     [member], to_host=(mode == "ooc"), donate=donate,
                     prefetch=prefetch, residents=residents, capture=capture,
-                    mesh=mesh)
+                    mesh=mesh, seq=seq)
                 residents = [entry] if entry is not None else None
                 disk_all.update(member.disk_stores)
         metrics.inc("pass_seconds", time.perf_counter() - t_pass)
@@ -762,8 +773,6 @@ def _run_epilogue(ps, prog, sink_finals, epi_sources, smalls, bindings,
     t0 = time.perf_counter()
     with TRACER.span("epilogue", idx=ps.idx):
         outs = prog.epilogue(sink_finals, epi_vals, smalls, bindings)
-        if TRACER.enabled:
-            jax.block_until_ready(outs)
     metrics.inc("epilogue_seconds", time.perf_counter() - t0)
     return outs
 
@@ -894,7 +903,7 @@ def _catch_up(members, maps, stacks, joined, group_pairs, rows: int,
 def _run_stream_group(members, *, to_host: bool, donate: bool = True,
                       prefetch: Optional[bool] = None, residents=None,
                       capture: bool = False, admit=None,
-                      depth: Optional[int] = None, mesh=None):
+                      depth: Optional[int] = None, mesh=None, seq=None):
     """Stream ONE co-scheduled group of member passes partition by
     partition: one prefetcher drive over the UNION of the members' staged
     sources, every member's step consuming each staged partition while it
@@ -922,13 +931,18 @@ def _run_stream_group(members, *, to_host: bool, donate: bool = True,
     single boundary order to splice into, so gated streams run unsharded
     (fm.serve instead serializes admission under a mesh — late requests
     wait for the next window; see Engine._run_group).
+
+    ``seq`` is the sweep's sequence id, carried by its ``stream`` span and
+    its prefetcher's ``stage`` spans (the caller's ``pass`` id; a new one
+    when None).
     """
     from .. import storage  # deferred: storage depends on core.matrix
 
+    seq = next_seq() if seq is None else seq
     if mesh is not None and admit is None:
         return _run_sharded_stream(members, mesh, to_host=to_host,
                                    donate=donate, prefetch=prefetch,
-                                   depth=depth)
+                                   depth=depth, seq=seq)
 
     n = members[0].ps.long_dim
     # Partition schedules in one group are power-of-two row counts over the
@@ -963,13 +977,13 @@ def _run_stream_group(members, *, to_host: bool, donate: bool = True,
             depth = storage.negotiate_depth(len(members), part_nbytes)
         parts = storage.PartitionPrefetcher(
             group_pairs, rows, n, donate=donate, depth=depth,
-            reuse=reuse_map)
+            reuse=reuse_map, seq=seq)
     else:
         parts = _inline_partitions(group_pairs, rows, n, donate,
                                    reuse=reuse_map)
     try:
         with TRACER.span("stream", members=len(members), rows=rows,
-                         reused=len(reuse_map or ())):
+                         reused=len(reuse_map or ()), seq=seq):
             for start, stop, blocks in parts:
                 if admit is not None:
                     for new_member in admit(start, stop):
@@ -1015,7 +1029,7 @@ def _to_device(tree, dev):
 
 def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
                         prefetch: Optional[bool] = None,
-                        depth: Optional[int] = None):
+                        depth: Optional[int] = None, seq=None):
     """Shard a group's partition sweep across the mesh's data axis
     (ISSUE 9 tentpole — the paper's partition-per-thread NUMA mapping,
     §III-D, as partition-range-per-device):
@@ -1116,7 +1130,7 @@ def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
             if prefetch:
                 parts = storage.PartitionPrefetcher(
                     group_pairs, rows, hi, row_start=lo, donate=donate,
-                    depth=depth, device=dev)
+                    depth=depth, device=dev, seq=seq)
             else:
                 parts = _inline_partitions(group_pairs, rows, hi, donate,
                                            row_start=lo, device=dev)
@@ -1138,7 +1152,7 @@ def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
                     parts.close()
 
     with TRACER.span("stream", members=len(members), rows=rows,
-                     shards=len(shards)):
+                     shards=len(shards), seq=seq):
         if len(shards) == 1:
             drive(0)
         else:

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import dtypes
+from ..observability import metrics
+from ..observability.trace import TRACER
 
 # ---------------------------------------------------------------------------
 # Partition-size policy
@@ -370,7 +372,20 @@ def conv_FM2R(mat: FMMatrix) -> np.ndarray:
     if mat.is_virtual:
         from . import materialize as _mat
         mat = _mat.materialize(mat)[0]
-    return np.asarray(mat.logical_data())
+    return fetch(mat.logical_data())
+
+
+def fetch(data) -> np.ndarray:
+    """``data`` as a host numpy array: the one place a result the caller
+    asked for (``conv_FM2R``, ``as_scalar``) comes from the device.  A
+    device array's transfer runs in a ``fetch`` span and is counted
+    (``host_fetches``)."""
+    if not isinstance(data, jax.Array):
+        return np.asarray(data)
+    with TRACER.span("fetch"):
+        out = np.asarray(data)
+    metrics.inc("host_fetches")
+    return out
 
 
 def conv_store(mat: FMMatrix, where: str, *, name: str = "") -> FMMatrix:

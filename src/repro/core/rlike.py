@@ -745,7 +745,7 @@ def inspect_iterations():
 
 def as_scalar(x) -> float:
     (r,) = materialize(x) if _fm(x).is_virtual else (x,)
-    return float(np.asarray(_fm(r).logical_data()).reshape(()))
+    return float(matrix_mod.fetch(_fm(r).logical_data()).reshape(()))
 
 
 def as_np(x) -> np.ndarray:

@@ -100,6 +100,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                                bq=bq, bk=bk, seq_len=skv)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),

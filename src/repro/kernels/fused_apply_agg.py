@@ -182,6 +182,7 @@ def fused_apply_agg(x, chains, *, acc_dtype: str = "float32",
     kernel = functools.partial(_chain_kernel, chains=chains, block=bc)
     outs = pl.pallas_call(
         kernel,
+        name="fused_apply_agg",
         grid=(xt.shape[1] // bc,),
         in_specs=[
             pl.BlockSpec((p, bc), lambda i: (0, i)),

@@ -52,6 +52,7 @@ def gram(x, *, block_rows: int = 0, interpret: bool | None = None):
     xt = lanes(x, bc)  # zero pad: neutral for sum-product
     return pl.pallas_call(
         _gram_kernel,
+        name="gram",
         grid=(xt.shape[1] // bc,),
         in_specs=[pl.BlockSpec((p, bc), lambda i: (0, i))],
         out_specs=pl.BlockSpec((p, p), lambda i: (0, 0)),
@@ -88,6 +89,7 @@ def xty(x, y, *, block_rows: int = 0, interpret: bool | None = None):
     yt = lanes(y, bc)
     return pl.pallas_call(
         _xty_kernel,
+        name="xty",
         grid=(xt.shape[1] // bc,),
         in_specs=[pl.BlockSpec((p, bc), lambda i: (0, i)),
                   pl.BlockSpec((q, bc), lambda i: (0, i))],

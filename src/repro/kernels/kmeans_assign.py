@@ -96,6 +96,7 @@ def kmeans_assign(x, centers, *, block_rows: int = 0,
     kernel = functools.partial(_kernel, block=bc)
     lab, sums, cnts, wss = pl.pallas_call(
         kernel,
+        name="kmeans_assign",
         grid=(xt.shape[1] // bc,),
         in_specs=[
             pl.BlockSpec((p, bc), lambda i: (0, i)),

@@ -84,6 +84,7 @@ def spmm_gram(cols, vals, *, ncol: int, block_rows: int = 0,
     kernel = functools.partial(_spmm_gram_kernel, ncol=ncol)
     return pl.pallas_call(
         kernel,
+        name="spmm_gram",
         grid=(ct.shape[1] // bc,),
         in_specs=[pl.BlockSpec((kmax, bc), lambda i: (0, i)),
                   pl.BlockSpec((kmax, bc), lambda i: (0, i))],
@@ -125,6 +126,7 @@ def spmm_xty(cols, vals, y, *, ncol: int, block_rows: int = 0,
     kernel = functools.partial(_spmm_xty_kernel, ncol=ncol)
     return pl.pallas_call(
         kernel,
+        name="spmm_xty",
         grid=(ct.shape[1] // bc,),
         in_specs=[pl.BlockSpec((kmax, bc), lambda i: (0, i)),
                   pl.BlockSpec((kmax, bc), lambda i: (0, i)),
@@ -168,6 +170,7 @@ def spmm_wgram(cols, vals, w, *, ncol: int, block_rows: int = 0,
     kernel = functools.partial(_spmm_wgram_kernel, ncol=ncol)
     return pl.pallas_call(
         kernel,
+        name="spmm_wgram",
         grid=(ct.shape[1] // bc,),
         in_specs=[pl.BlockSpec((kmax, bc), lambda i: (0, i)),
                   pl.BlockSpec((kmax, bc), lambda i: (0, i)),

@@ -61,6 +61,7 @@ def wgram(x, w, *, block_rows: int = 0, interpret: bool | None = None):
     wt = lanes(w.reshape(n, 1), bc)
     return pl.pallas_call(
         _wgram_kernel,
+        name="wgram",
         grid=(xt.shape[1] // bc,),
         in_specs=[pl.BlockSpec((p, bc), lambda i: (0, i)),
                   pl.BlockSpec((1, bc), lambda i: (0, i))],

@@ -5,14 +5,16 @@ The instrumentation substrate the execution engine records into
 benchmarks/serving layers read from:
 
 * `trace`   — nested timing spans with Chrome-trace/Perfetto export
-  (``fm.trace(...)`` / ``fm.trace_export(path)``);
+  (``fm.trace(...)`` / ``fm.trace_export(path)``), also in the JAX
+  profiler's trace while a profiler session is active;
 * `metrics` — thread-safe scoped counters/gauges/histograms behind the
   ``exec_stats()`` compatibility view, plus ``fm.collect_stats()`` for
   per-request isolation;
 * `explain` — the fused-plan pretty-printer behind ``fm.explain(x)``.
 
-`trace` and `metrics` are stdlib-only (core imports this package at module
-load); `explain` imports core lazily inside its functions.
+`metrics` is stdlib-only and `trace` needs only jax's profiler hook, with
+a fallback (core imports this package at module load); `explain` imports
+core lazily inside its functions.
 """
 from . import explain, metrics, trace                       # noqa: F401
 from .explain import explain as explain_outputs, explain_plan  # noqa: F401

@@ -23,11 +23,12 @@ Metric kinds:
     most recent execution, published atomically at execution end — never
     half-updated by an interleaved materialize);
   * ``observe(name, v)`` — histogram summary (count/total/min/max), e.g.
-    prefetch-queue occupancy samples.
+    the serving layer's queue depth and request latency.
 
 ``Scope.stats()`` returns a plain dict: counters and values verbatim,
 histograms as ``{name: {count, total, min, max, mean}}``, plus derived
-rates — ``stream_bandwidth_bytes_s`` (slow-tier staging read bandwidth),
+rates — ``stream_bandwidth_bytes_s`` (what the stager sustains: slow-tier
+bytes over the time until ``device_put`` has taken them),
 ``prefetch_wait_frac`` (fraction of streaming wall time the compute thread
 spent blocked on the staging queue) and ``plan_cache_hit_ratio``.
 

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import metrics
-from ..observability.trace import TRACER
+from ..observability.trace import TRACER, name_os_thread
 
 _DONE = object()
 
@@ -63,68 +63,83 @@ def negotiate_depth(n_members: int, partition_nbytes: int,
 
 
 def stage_block(mat, start: int, stop: int, *, donate: bool = True,
-                to_device: bool = True, device=None):
+                to_device: bool = True, device=None, seq=None):
     """Read one I/O-level partition from ``mat`` and stage it for the fused
     step — the single definition of the staging rules, shared by the
     prefetch thread and the synchronous (prefetch-off) path:
 
-    * slow-tier (numpy/memmap) blocks are made contiguous (the actual disk
-      read for a memmap slice) and ``device_put`` — dispatch is async, so
-      the H2D copy overlaps downstream compute;
+    * slow-tier (numpy/memmap) blocks are made contiguous and
+      ``device_put`` — the transfer itself is async, so the H2D copy
+      overlaps downstream compute;
     * device-resident blocks are defensively copied when the consumer will
       donate them (donation must not consume the source buffer).
 
     Emits a ``stage`` span on whichever thread runs it (the prefetch
-    worker's own track when pipelined) and feeds the slow-tier read
-    bandwidth counters (``stage_bytes_read`` / ``stage_read_seconds``:
-    memmap/numpy reads only — device-resident blocks involve no tier read).
+    worker's own track when pipelined), carrying the prefetcher's stream
+    sequence id ``seq``, with each ``device_put`` in a nested ``stage_put``
+    span.  Counters:
+
+    * ``stage_seconds`` — the whole call, on any tier;
+    * ``stage_bytes_read`` / ``stage_read_seconds`` — slow-tier blocks
+      only (a device-resident block involves no tier read): the bytes, and
+      the time from the slice until ``device_put`` has taken them.  For a
+      contiguous host slice ``np.ascontiguousarray`` copies nothing, so
+      the read itself (a memmap's page faults) and the host's layout of
+      the block for the device both happen inside ``device_put``.  With
+      ``to_device=False`` the time ends at the contiguous copy.
 
     ``device`` pins the staged block to one device of a mesh (the sharded
     partition loop stages each shard's rows onto that shard's device);
     ``None`` keeps the default uncommitted placement.
     """
     t0 = time.perf_counter()
+    with TRACER.span("stage", start=int(start), stop=int(stop), seq=seq):
+        blk = _stage(mat, start, stop, donate, to_device, device)
+    metrics.inc("stage_seconds", time.perf_counter() - t0)
+    return blk
+
+
+def _device_put(x, device):
+    """``jax.device_put`` inside a ``stage_put`` span."""
+    with TRACER.span("stage_put"):
+        return jax.device_put(x, device)
+
+
+def _stage(mat, start: int, stop: int, donate: bool, to_device: bool,
+           device):
+    t0 = time.perf_counter()
     blk = mat.block(start, stop)
     if type(blk).__name__ == "SparseBlock":
         # Sparse (ELL) partition: a (cols, vals) pytree.  Same rules as the
-        # dense branches, applied leaf-wise — host slabs are the slow-tier
-        # read (contiguous + async device_put), device slabs are copied
-        # only when the consumer will donate them.
+        # dense branches, applied leaf-wise.
         from ..core.sparse import SparseBlock
         if isinstance(blk.vals, np.ndarray):
             cols = np.ascontiguousarray(blk.cols)
             vals = np.ascontiguousarray(blk.vals)
+            if to_device:
+                cols, vals = _device_put((cols, vals), device)
             metrics.inc("stage_bytes_read", cols.nbytes + vals.nbytes)
             metrics.inc("stage_read_seconds", time.perf_counter() - t0)
-            if to_device:
-                cols = jax.device_put(cols, device)
-                vals = jax.device_put(vals, device)
-            blk = SparseBlock(cols, vals, blk.ncol)
-        elif device is not None:
-            blk = SparseBlock(jax.device_put(blk.cols, device),
-                              jax.device_put(blk.vals, device), blk.ncol)
-        elif donate:
-            blk = SparseBlock(jnp.copy(blk.cols), jnp.copy(blk.vals),
-                              blk.ncol)
-        TRACER.record("stage", t0, time.perf_counter(),
-                      {"start": int(start), "stop": int(stop)})
+            return SparseBlock(cols, vals, blk.ncol)
+        if device is not None:
+            return SparseBlock(*_device_put((blk.cols, blk.vals), device),
+                               blk.ncol)
+        if donate:
+            return SparseBlock(jnp.copy(blk.cols), jnp.copy(blk.vals),
+                               blk.ncol)
         return blk
     if isinstance(blk, np.ndarray):
         blk = np.ascontiguousarray(blk)
-        # The slow-tier read is complete once the block is contiguous in
-        # RAM; device_put below is async dispatch, not read time.
+        if to_device:
+            blk = _device_put(blk, device)
         metrics.inc("stage_bytes_read", blk.nbytes)
         metrics.inc("stage_read_seconds", time.perf_counter() - t0)
-        if to_device:
-            blk = jax.device_put(blk, device)
     elif device is not None:
         # Cross-device copy: commits to the shard's device and leaves the
         # resident source buffer untouched, so donation stays safe.
-        blk = jax.device_put(blk, device)
+        blk = _device_put(blk, device)
     elif donate:
         blk = jnp.copy(blk)
-    TRACER.record("stage", t0, time.perf_counter(),
-                  {"start": int(start), "stop": int(stop)})
     return blk
 
 
@@ -178,7 +193,7 @@ class PartitionPrefetcher:
                  partition_rows: int, long_dim: int, *, depth: int = 2,
                  donate: bool = True, stage_to_device: bool = True,
                  reuse: Optional[dict] = None, row_start: int = 0,
-                 device=None):
+                 device=None, seq=None):
         if depth < 1:
             raise ValueError("prefetch depth must be >= 1")
         self.sources = list(sources)
@@ -189,6 +204,9 @@ class PartitionPrefetcher:
         # range, staged onto that shard's ``device``.
         self.row_start = int(row_start)
         self.device = device
+        # The sequence id of the stream this prefetcher stages for: its
+        # ``stage`` spans carry it.
+        self.seq = seq
         self.donate = donate
         self.stage_to_device = stage_to_device
         # {node_id: staged block} for the FINAL partition: when the previous
@@ -211,6 +229,7 @@ class PartitionPrefetcher:
 
     # -- staging thread --------------------------------------------------------
     def _worker(self):
+        name_os_thread(self._thread.name)
         with metrics.use_scopes(self._scopes):
             try:
                 start = self.row_start
@@ -229,13 +248,12 @@ class PartitionPrefetcher:
                             blocks[nid] = stage_block(
                                 mat, start, stop, donate=self.donate,
                                 to_device=self.stage_to_device,
-                                device=self.device)
+                                device=self.device, seq=self.seq)
                         except Exception as exc:
                             raise PrefetchError(
                                 f"prefetch thread failed staging rows "
                                 f"[{start}, {stop}) of source "
                                 f"{_source_name(mat)!r}: {exc!r}") from exc
-                    metrics.observe("prefetch_queue_depth", self._q.qsize())
                     if not self._put((start, stop, blocks)):
                         return
                     start = stop
@@ -257,12 +275,11 @@ class PartitionPrefetcher:
     def __iter__(self) -> Iterator[tuple]:
         while True:
             t0 = time.perf_counter()
-            item = self._q.get()
-            t1 = time.perf_counter()
+            with TRACER.span("prefetch_wait"):
+                item = self._q.get()
             # Time the compute thread spent blocked on the staging queue:
             # the numerator of prefetch_wait_frac (pipeline-fill included).
-            metrics.inc("prefetch_wait_seconds", t1 - t0)
-            TRACER.record("prefetch_wait", t0, t1)
+            metrics.inc("prefetch_wait_seconds", time.perf_counter() - t0)
             if item is _DONE:
                 self._closed = True
                 return

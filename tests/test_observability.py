@@ -318,3 +318,154 @@ def test_plan_explain_method_matches_fm_explain():
     Z = fm.scale(X)
     assert Plan([Z.m]).explain(backend="xla") == fm.explain(
         Z, backend="xla")
+
+
+# ---------------------------------------------------------------------------
+# The profiler sink: spans in the JAX profiler's trace
+# ---------------------------------------------------------------------------
+
+def _profiler(log_dir):
+    """A profiler session as the benchmark opens one: without the Python
+    tracer, whose events would reach a new thread before the thread has
+    its name."""
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+def _profile_lines(log_dir):
+    """{line: [(span name, {arg: value})]} of the ``fm.*`` events in the
+    one ``.xplane.pb`` under ``log_dir``, per host line."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = [(ev.name.split("#")[0], dict(ev.stats))
+                   for ev in line.events if ev.name.startswith("fm.")]
+            if evs:
+                lines.append((line.name, evs))
+    return lines
+
+
+@pytest.fixture()
+def profiled_ooc_kmeans(tmp_path, small_partitions):
+    """One Lloyd step over a host-RAM X, streamed by the prefetcher, under
+    ``jax.profiler.trace``; the ``fm.trace()`` recording stays off."""
+    from repro.algorithms.kmeans import kmeans_iteration
+    X = fm.conv_R2FM(_arr(n=2048, p=4, seed=5), host=True)
+    centers = _arr(n=3, p=4, seed=6)
+    kmeans_iteration(X, centers)
+    metrics.reset()
+    with _profiler(tmp_path / "prof"):
+        kmeans_iteration(X, centers)
+    return _profile_lines(tmp_path / "prof"), metrics.stats()
+
+
+def test_profiler_sink_puts_spans_on_the_working_threads(
+        profiled_ooc_kmeans):
+    lines, st = profiled_ooc_kmeans
+    assert not TRACER.enabled and TRACER.events() == []
+    names = {name: {n for n, _ in evs} for name, evs in lines}
+    compute = [n for n, got in names.items() if "fm.pass" in got]
+    assert len(compute) == 1
+    assert {"fm.plan", "fm.pass", "fm.stream", "fm.partition",
+            "fm.device_step", "fm.combine", "fm.prefetch_wait",
+            "fm.fetch"} <= names[compute[0]]
+    assert "fm.stage" not in names[compute[0]]
+    assert {"fm.stage", "fm.stage_put"} <= names["fm-prefetch"]
+    # One span of each per partition step and per fetch: each
+    # partition's labels to their host buffer, then the three results the
+    # caller fetches and counts (sums, counts and the objective).
+    stages = [a for n, a in dict(lines)["fm-prefetch"] if n == "fm.stage"]
+    assert len(stages) == st["partition_steps"] > 2
+    fetches = [n for n, _ in dict(lines)[compute[0]] if n == "fm.fetch"]
+    assert st["host_fetches"] == 3
+    assert len(fetches) == st["partition_steps"] + st["host_fetches"]
+
+
+def test_stage_spans_carry_the_pass_sequence_id(profiled_ooc_kmeans):
+    lines, _ = profiled_ooc_kmeans
+    by_line = dict(lines)
+    compute = next(evs for _, evs in lines
+                   if any(n == "fm.pass" for n, _ in evs))
+    (pass_seq,) = {a["seq"] for n, a in compute if n == "fm.pass"}
+    (stream_seq,) = {a["seq"] for n, a in compute if n == "fm.stream"}
+    stage_seqs = {a["seq"] for n, a in by_line["fm-prefetch"]
+                  if n == "fm.stage"}
+    assert pass_seq == stream_seq and stage_seqs == {pass_seq}
+
+
+def test_both_sinks_record_together(tmp_path, small_partitions):
+    X = fm.conv_R2FM(_arr(n=1024, seed=7), host=True)
+    with _profiler(tmp_path / "prof"), fm.trace():
+        fm.conv_FM2R(fm.crossprod(X))
+    recorded = collections.Counter(e["name"] for e in fm.trace_events())
+    profiled = collections.Counter(
+        n[len("fm."):] for _, evs in _profile_lines(tmp_path / "prof")
+        for n, _ in evs)
+    assert recorded == profiled
+    assert recorded["stage_put"] == recorded["stage"] > 1
+
+
+def test_span_is_the_null_span_with_both_sinks_off():
+    from repro.observability import trace
+    assert not TRACER.enabled and not trace.profiler_active()
+    assert TRACER.span("device_step", rows=1) is trace._NULL_SPAN
+
+
+def test_span_is_a_trace_me_under_the_profiler(tmp_path):
+    from repro.observability import trace
+    with _profiler(tmp_path):
+        assert trace.profiler_active()
+        assert TRACER.span("pass", seq=1) is not trace._NULL_SPAN
+    assert TRACER.span("pass", seq=1) is trace._NULL_SPAN
+
+
+def test_no_device_synchronization_in_the_executor():
+    """Spans never block on device values: tracing leaves the pipeline's
+    asynchronous dispatch as it is."""
+    import inspect
+
+    from repro.observability import trace
+    assert "block_until_ready" not in inspect.getsource(mz)
+    assert "block_until_ready" not in inspect.getsource(trace)
+
+
+def test_stage_read_seconds_holds_the_device_put(small_partitions):
+    """For a host block the read ends once ``device_put`` has taken the
+    bytes: the put's time (its ``stage_put`` spans) lies inside the
+    read's, and both inside the whole staging call's."""
+    X = fm.conv_R2FM(_arr(n=4096, seed=8), host=True)
+    with fm.collect_stats() as scope, fm.trace():
+        fm.materialize(fm.crossprod(X), mode="ooc")
+    st = scope.stats()
+    put_s = sum(e["dur"] for e in fm.trace_events()
+                if e["name"] == "stage_put") / 1e6
+    assert st["stage_bytes_read"] == X.m.nbytes()
+    assert 0 < put_s <= st["stage_read_seconds"] <= st["stage_seconds"]
+    assert st["stream_bandwidth_bytes_s"] == pytest.approx(
+        st["stage_bytes_read"] / st["stage_read_seconds"])
+
+
+def test_plan_and_fetch_counters():
+    from repro.algorithms.kmeans import kmeans_iteration
+    X = fm.conv_R2FM(_arr(n=512, seed=9))
+    centers = _arr(n=3, seed=10)
+    with fm.collect_stats() as scope:
+        for _ in range(2):
+            centers = kmeans_iteration(X, centers)[0]
+    st = scope.stats()
+    # Sums, counts and the objective: three device results per iteration.
+    assert st["host_fetches"] == 6
+    assert st["plan_seconds"] > 0
+    # A host array is no fetch.
+    with fm.collect_stats() as scope:
+        fm.conv_FM2R(fm.conv_R2FM(_arr(n=8), host=True))
+    assert "host_fetches" not in scope.stats()

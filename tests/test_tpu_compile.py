@@ -13,6 +13,8 @@ The topology is described inside a fixture, never at import, so every
 test worker collects the same tests and only the worker that runs them
 loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -179,6 +181,13 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     unit = _unit(*outs)
     assert unit.kernel == kernel
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(make(unit)).lower(*args).compile()
+    text = jax.jit(make(unit)).lower(*args).compile().as_text()
     # The kernel is a Mosaic custom call, not an interpreted loop.
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in text
+    # Its launch carries the kernel's own name (``%<kernel>`` or
+    # ``%<kernel>.<n>``), which is how a device trace names its time.
+    launches = [line.strip().removeprefix("ROOT ")
+                for line in text.splitlines() if "tpu_custom_call" in line]
+    named = re.compile(
+        "%" + re.escape(kernel) + r"(\.\d+)? = .*custom-call\(")
+    assert any(named.match(op) for op in launches), launches
