@@ -17,8 +17,10 @@ Executes a fused `fusion.Plan` in one of three modes:
   partition i+1 overlaps the compute of partition i (the paper's
   I/O/compute overlap).  The fused step consumes staged blocks with buffer
   donation (the paper's memory-chunk recycling), and long-dimension
-  outputs write through to preallocated host buffers or — with
-  ``save='disk'`` — stream into a preallocated on-disk matrix (spill).
+  outputs copy to the host asynchronously and write through, as many
+  partitions behind the step as the prefetcher runs ahead of it, to
+  preallocated host buffers or — with ``save='disk'`` — into a
+  preallocated on-disk matrix (spill).
 
 Sinks accumulate partition partials and merge with the aggregation VUDF's
 ``combine`` — identical in all three modes, which is exactly why the paper's
@@ -27,6 +29,7 @@ intensity is high enough.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
@@ -377,11 +380,16 @@ class _PassExec:
     the metrics scopes captured when the request joined the batch; the
     runners adopt them around this member's compute so per-request
     attribution reports the member's OWN share, not the group's.
+
+    ``pending`` holds the partitions whose row-addressed long outputs (host
+    buffer or disk store) are still on their way to the host: one
+    ``(start, stop, [(nid, device_value), ...])`` per partition, oldest
+    first (`route_outputs`, `drain`).
     """
 
     __slots__ = ("ps", "prog", "sources", "smalls", "epi_sources",
                  "bindings", "out_nodes", "scopes", "accs", "out_parts",
-                 "host_bufs", "disk_stores", "finals", "epi_outs")
+                 "host_bufs", "disk_stores", "finals", "epi_outs", "pending")
 
     def __init__(self, ps, prog, sources, smalls, epi_sources, bindings, *,
                  out_nodes=None, scopes=()):
@@ -402,18 +410,67 @@ class _PassExec:
         self.disk_stores: dict[int, object] = {}
         self.finals = None
         self.epi_outs = None
+        self.pending = collections.deque()
 
-    def route_outputs(self, start: int, stop: int, outputs: dict):
+    def route_outputs(self, start: int, stop: int, outputs: dict,
+                      window: int):
+        """Route one partition step's long outputs.  Device-resident ones
+        append to ``out_parts``.  A row-addressed one starts its copy to
+        the host and waits in ``pending``; the partitions more than
+        ``window`` steps old are then written to their targets.  The
+        compute thread so never waits for the step it just dispatched."""
+        queued = []
         for nid, val in outputs.items():
-            if nid in self.disk_stores:
-                with TRACER.span("fetch"):
-                    val = np.asarray(val)
-                self.disk_stores[nid].write_rows(start, val)
-            elif nid in self.host_bufs:
-                with TRACER.span("fetch"):
-                    self.host_bufs[nid][start:stop] = np.asarray(val)
+            if nid in self.disk_stores or nid in self.host_bufs:
+                with TRACER.span("fetch_start"):
+                    val.copy_to_host_async()
+                queued.append((nid, val))
             else:
                 self.out_parts[nid].append(val)
+        if queued:
+            metrics.inc("deferred_output_copies", len(queued))
+            self.pending.append((start, stop, queued))
+        self.drain(keep=window)
+
+    def drain(self, keep: int = 0):
+        """Write pending partitions' outputs to their targets, oldest
+        first, until ``keep`` partitions are left pending."""
+        while len(self.pending) > keep:
+            self._write_rows(*self.pending.popleft())
+
+    def _write_rows(self, start: int, stop: int, queued: list):
+        t0 = time.perf_counter()
+        for nid, val in queued:
+            with TRACER.span("fetch"):
+                rows = np.asarray(val)
+            if nid in self.disk_stores:
+                self.disk_stores[nid].write_rows(start, rows)
+            else:
+                self.host_bufs[nid][start:stop] = rows
+        metrics.inc("output_drain_seconds", time.perf_counter() - t0)
+
+
+def _output_window(prefetch: bool, depth: Optional[int]) -> int:
+    """How many partitions' long outputs may wait for their host copies:
+    as many as the stream's staged inputs lead the step, the configured
+    prefetch depth where no prefetcher runs."""
+    from .. import storage  # deferred: storage depends on core.matrix
+    return depth if prefetch else storage.get_conf("prefetch_depth")
+
+
+@contextlib.contextmanager
+def _draining(members):
+    """Around a partition loop: when it completes, write out every
+    member's pending long outputs; whether or not it does, drop what is
+    left, so a failed sweep, whose results never register, holds no
+    device values."""
+    try:
+        yield
+        for m in members:
+            m.drain()
+    finally:
+        for m in members:
+            m.pending.clear()
 
 
 def _member_stack(member: _PassExec):
@@ -878,8 +935,10 @@ def _catch_up(members, maps, stacks, joined, group_pairs, rows: int,
     max_join = max(joined.values())
     late_keys = {key for idx in joined for key in maps[idx].values()}
     pairs = [(key, mat) for key, mat in group_pairs if key in late_keys]
+    window = _output_window(False, None)
     start = 0
-    with TRACER.span("catch_up", members=len(joined), upto=max_join):
+    with TRACER.span("catch_up", members=len(joined), upto=max_join), \
+            _draining(members):
         while start < max_join:
             stop = min(start + rows, max_join)
             blocks = {key: stage_block(mat, start, stop, donate=donate)
@@ -896,7 +955,7 @@ def _catch_up(members, maps, stacks, joined, group_pairs, rows: int,
                         outputs = _member_step(
                             m, blocks, mp, start, stop,
                             donate_blocks=donate_blocks, idx=i)
-                    m.route_outputs(start, stop, outputs)
+                    m.route_outputs(start, stop, outputs, window)
             start = stop
 
 
@@ -981,9 +1040,11 @@ def _run_stream_group(members, *, to_host: bool, donate: bool = True,
     else:
         parts = _inline_partitions(group_pairs, rows, n, donate,
                                    reuse=reuse_map)
+    window = _output_window(prefetch, depth)
     try:
         with TRACER.span("stream", members=len(members), rows=rows,
-                         reused=len(reuse_map or ()), seq=seq):
+                         reused=len(reuse_map or ()), seq=seq), \
+                _draining(members):
             for start, stop, blocks in parts:
                 if admit is not None:
                     for new_member in admit(start, stop):
@@ -1005,7 +1066,7 @@ def _run_stream_group(members, *, to_host: bool, donate: bool = True,
                             outputs = _member_step(
                                 m, blocks, mp, start, stop,
                                 donate_blocks=donate_blocks, idx=i)
-                        m.route_outputs(start, stop, outputs)
+                        m.route_outputs(start, stop, outputs, window)
                     if capture and is_final:
                         captured = _Resident(
                             rows, n,
@@ -1091,6 +1152,7 @@ def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
                     and any(mat.on_host for _, mat in group_pairs))
     if prefetch and depth is None:
         depth = storage.negotiate_depth(len(members), rows * row_bytes)
+    window = _output_window(prefetch, depth)
 
     # Per-shard executor clones: the SAME compiled per-pass program run as
     # per-device executors, one row range each.  Bindings (earlier passes'
@@ -1135,7 +1197,8 @@ def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
                 parts = _inline_partitions(group_pairs, rows, hi, donate,
                                            row_start=lo, device=dev)
             try:
-                with TRACER.span("shard", idx=si, start=lo, stop=hi):
+                with TRACER.span("shard", idx=si, start=lo, stop=hi), \
+                        _draining(clones):
                     for start, stop, blocks in parts:
                         with TRACER.span("partition", start=start,
                                          stop=stop, shard=si):
@@ -1146,7 +1209,8 @@ def _run_sharded_stream(members, mesh, *, to_host: bool, donate: bool = True,
                                     outputs = _member_step(
                                         sm, blocks, mp, start, stop,
                                         donate_blocks=donate_blocks, idx=i)
-                                sm.route_outputs(start, stop, outputs)
+                                sm.route_outputs(start, stop, outputs,
+                                                 window)
             finally:
                 if hasattr(parts, "close"):
                     parts.close()

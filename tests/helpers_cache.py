@@ -60,6 +60,38 @@ def assert_activity(act: CacheActivity, **expected):
 
 
 # ---------------------------------------------------------------------------
+# Deferred long-output routing
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass
+class PassExecWatch:
+    """What `watch_pass_execs` saw: every `_PassExec` built, and after each
+    partition's ``route_outputs`` the member's pending queue length beside
+    the window it was given."""
+
+    members: list = dataclasses.field(default_factory=list)
+    pending: list = dataclasses.field(default_factory=list)
+
+
+def watch_pass_execs(monkeypatch) -> PassExecWatch:
+    """Swap the executor's `_PassExec` for a subclass that records into the
+    returned `PassExecWatch`."""
+    seen = PassExecWatch()
+
+    class Watched(mz._PassExec):
+        def __init__(self, *args, **kw):
+            super().__init__(*args, **kw)
+            seen.members.append(self)
+
+        def route_outputs(self, start, stop, outputs, window):
+            super().route_outputs(start, stop, outputs, window)
+            seen.pending.append((len(self.pending), window))
+
+    monkeypatch.setattr(mz, "_PassExec", Watched)
+    return seen
+
+
+# ---------------------------------------------------------------------------
 # Staging fault injection (multi-pass interruption tests)
 # ---------------------------------------------------------------------------
 

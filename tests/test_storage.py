@@ -522,13 +522,16 @@ def test_registry_cleanup_runs_at_interpreter_exit():
 # Prefetcher shutdown on interrupted streams (ISSUE 9 satellite)
 # ---------------------------------------------------------------------------
 
-def test_interrupted_stream_leaks_no_prefetcher_state(data_dir):
+def test_interrupted_stream_leaks_no_prefetcher_state(data_dir,
+                                                     monkeypatch):
     """A staging fault mid-stream must tear the prefetch pipeline down
     completely: worker thread joined, queued staged partitions drained
     (not pinned on device), TLS residents cleared — thread count and
-    pinned-partition census return to baseline."""
+    pinned-partition census return to baseline.  The long output whose
+    host copies were pending when the fault came registers no result, and
+    its member holds no pending device values."""
     import threading, time
-    from helpers_cache import StagingFault
+    from helpers_cache import StagingFault, watch_pass_execs
     from repro.core import matrix as matrix_mod
 
     old_budget = matrix_mod.IO_PARTITION_BYTES
@@ -547,9 +550,15 @@ def test_interrupted_stream_leaks_no_prefetcher_state(data_dir):
 
         store.block = flaky_block  # instance attr shadows the method
 
+        seen = watch_pass_execs(monkeypatch)
+        Y = X * X
         n_threads0 = threading.active_count()
         with pytest.raises((StagingFault, storage.PrefetchError)):
-            fm.materialize(fm.colSums(X * X), mode="ooc", prefetch=True)
+            fm.materialize(fm.colSums(Y), Y, mode="ooc", prefetch=True)
+        # Two partitions staged before the fault, both still pending.
+        assert max(n for n, _ in seen.pending) == 2
+        assert Y.m.is_virtual, "an interrupted pass registered its output"
+        assert all(not m.pending for m in seen.members)
 
         deadline = time.time() + 10
         while time.time() < deadline and (
